@@ -30,6 +30,7 @@ import sys
 from repro import obs
 from repro.core.profiles import A100_80GB, H100_96GB
 from repro.core.tpu_profiles import TPU_V5E_POD
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import profile
 
 log = logging.getLogger("repro.bench.calibrate")
@@ -107,4 +108,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -23,6 +23,7 @@ import time
 from typing import Dict, List
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import profile
 
 log = logging.getLogger("repro.bench.kernels")
@@ -101,6 +102,7 @@ def main(argv=None) -> int:
         "args": {"preset": args.preset, "reps": args.reps,
                  "warmup": args.warmup},
         "host": host,
+        "jax_device": obs.device_snapshot(),
         "kernels": run(args.preset, args.reps, args.warmup),
     }
     if obs.write_report(args.json, report, KERNEL_BENCH_SCHEMA):
@@ -111,4 +113,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -92,6 +92,7 @@ from repro.core.profiles import A100_80GB
 from repro.core.simulator import TestCase, generate_test_case
 from repro.core.tpu_profiles import TPU_V5E_POD
 from repro.core.traffic import DiurnalRate, FlashCrowd, ModelTraffic, generate_requests
+from repro.launch.compile_cache import enable_compile_cache
 
 #: human-readable output channel (tables, timings) — stderr via logging, so
 #: stdout never interleaves human text with telemetry/JSON consumers.
@@ -998,4 +999,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -15,6 +15,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import kernel_bench, roofline, solver_scaling
 from .placement_bench import print_table, run_case
 
@@ -70,4 +72,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

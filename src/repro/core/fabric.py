@@ -36,10 +36,10 @@ True iff ``state.gpus[gid_g].can_place_at(profile_p, i)`` — property-tested
 in ``tests/test_fabric.py`` on randomized heterogeneous fleets.  The fast
 paths must pick byte-identical (gid, index) spots to the scalar policies.
 
-JAX is optional: kernels are written against the array-API subset shared by
-``numpy`` and ``jax.numpy``; with JAX present the batched variants are
-``jax.jit``-compiled (shapes are static per fleet, so each fleet shape
-compiles once), otherwise the numpy instantiation runs.
+Kernels are written against the array-API subset shared by ``numpy`` and
+``jax.numpy``: the batched variants are ``jax.jit``-compiled (shapes are
+static per fleet, so each fleet shape compiles once) and run on JAX's default
+device; ``use_jax=False`` runs the numpy instantiation, the parity reference.
 """
 from __future__ import annotations
 
@@ -48,21 +48,13 @@ import functools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..obs import get_telemetry
 from .profiles import DeviceModel, Profile
 from .state import ClusterState, Placement, Workload
-
-try:  # JAX is an optional dependency of the placement core.
-    import jax
-    import jax.numpy as jnp
-
-    _HAVE_JAX = True
-except ImportError:  # pragma: no cover - exercised on JAX-free installs
-    jax = None
-    jnp = None
-    _HAVE_JAX = False
 
 __all__ = [
     "FleetFabric",
@@ -74,15 +66,10 @@ __all__ = [
     "fabric_frag_aware_compact",
     "fabric_frag_aware_reconfigure",
     "replay_fresh_deploy",
-    "have_jax",
 ]
 
 #: preference rank sentinel for disallowed (profile, index) pairs.
 _NO_RANK = np.int32(32767)
-
-
-def have_jax() -> bool:
-    return _HAVE_JAX
 
 
 # ---------------------------------------------------------------------------
@@ -160,23 +147,22 @@ def _score_kernel(xp, occ, n_mem, n_gpu, extra_mem, mem_sl, cmp_sl):
 _feasible_np = functools.partial(_feasible_kernel, np)
 _score_np = functools.partial(_score_kernel, np)
 
-if _HAVE_JAX:
-    #: all-profiles variants: vmap over the profile axis of the per-profile
-    #: kernels -> (G, P, I) for the whole fleet in one compiled sweep.
-    _feasible_all_jit = jax.jit(
-        jax.vmap(
-            functools.partial(_feasible_kernel, jnp),
-            in_axes=(None, None, None, None, 0, 0, 0, None),
-            out_axes=1,
-        )
+#: all-profiles variants: vmap over the profile axis of the per-profile
+#: kernels -> (G, P, I) for the whole fleet in one compiled sweep.
+_feasible_all_jit = jax.jit(
+    jax.vmap(
+        functools.partial(_feasible_kernel, jnp),
+        in_axes=(None, None, None, None, 0, 0, 0, None),
+        out_axes=1,
     )
-    _score_all_jit = jax.jit(
-        jax.vmap(
-            functools.partial(_score_kernel, jnp),
-            in_axes=(None, None, None, None, 0, 0),
-            out_axes=1,
-        )
+)
+_score_all_jit = jax.jit(
+    jax.vmap(
+        functools.partial(_score_kernel, jnp),
+        in_axes=(None, None, None, None, 0, 0),
+        out_axes=1,
     )
+)
 
 
 def _feasible_all_np(occ, n_mem, me_used, me_cap, mem_sl, me_req, allowed, mask):
@@ -262,7 +248,7 @@ class FleetFabric:
     """
 
     def __init__(self, state: ClusterState, use_jax: Optional[bool] = None):
-        self.use_jax = _HAVE_JAX if use_jax is None else (use_jax and _HAVE_JAX)
+        self.use_jax = True if use_jax is None else use_jax
         self.gids: List[str] = state.ordered_gids()
         self.row_of: Dict[str, int] = {g: r for r, g in enumerate(self.gids)}
         devices: List[DeviceModel] = [state.gpus[g].device for g in self.gids]

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ._shardmap import shard_map
 
 __all__ = ["apply_moe_alltoall"]
 
@@ -127,7 +126,7 @@ def apply_moe_alltoall(
 
     tok_spec = P(daxes if len(daxes) > 1 else (daxes[0] if daxes else None))
     fn = partial(_local_moe, e_local=e_local, rep=rep, cap=cap, k=k)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(

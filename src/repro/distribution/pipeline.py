@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._shardmap import shard_map
 
 __all__ = ["gpipe"]
 
@@ -84,7 +83,7 @@ def gpipe(
         )
 
     pspec = jax.tree.map(lambda _: P(stage_axis), stage_params)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(pspec, P()),

@@ -15,8 +15,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _dec_kernel(
@@ -100,7 +104,7 @@ def decode_attention_pallas(
         kernel,
         grid=(b * hkv, 1, n_k),
         in_specs=[
-            _smem_spec(),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g, d), lambda bh, z, j: (bh, 0, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, z, j: (bh, j, 0)),
             pl.BlockSpec((1, block_k, dv), lambda bh, z, j: (bh, j, 0)),
@@ -108,7 +112,7 @@ def decode_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, g, dv), lambda bh, z, j: (bh, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hkv, 1, g, dv), q.dtype),
         scratch_shapes=[_vmem((g, dv)), _vmem((g, 128)), _vmem((g, 128))],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(lsc, qt.reshape(b * hkv, 1, g, d), kt, vt)
     return out.reshape(b, hkv, g, dv).reshape(b, 1, hq, dv)
@@ -121,9 +125,9 @@ def _dec_q8_kernel(
     len_ref,  # (B*Hkv, 1) int32 in SMEM
     q_ref,  # (1, 1, G, D)
     kq_ref,  # (1, block_k, D) int8
-    ks_ref,  # (1, block_k) f32
+    ks_ref,  # (1, 1, block_k) f32
     vq_ref,  # (1, block_k, Dv) int8
-    vs_ref,  # (1, block_k) f32
+    vs_ref,  # (1, 1, block_k) f32
     o_ref,  # (1, 1, G, Dv)
     acc_ref, m_ref, l_ref,
     *, scale: float, block_k: int, n_k: int,
@@ -142,12 +146,15 @@ def _dec_q8_kernel(
     @pl.when(k_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
-        # dequantize the tile in VMEM
-        k = kq_ref[0].astype(jnp.float32) * ks_ref[0][:, None]  # (bk, D)
-        v = vq_ref[0].astype(jnp.float32) * vs_ref[0][:, None]  # (bk, Dv)
+        k = kq_ref[0].astype(jnp.float32)  # (bk, D) int8 values
+        v = vq_ref[0].astype(jnp.float32)  # (bk, Dv) int8 values
+        # per-token scales run along the lane (key) axis of the score tile:
+        # q.(k_u * ks_u) == (q.k_u) * ks_u, and p @ (v * vs) == (p * vs) @ v
+        ks = ks_ref[0]  # (1, bk)
+        vs = vs_ref[0]  # (1, bk)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
+        ) * (ks * scale)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
         m_prev = m_ref[:, :1]
@@ -158,7 +165,7 @@ def _dec_q8_kernel(
         l_ref[:, :1] = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
         m_ref[:, :1] = m_new
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p * vs, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
 
     @pl.when(kj == n_k - 1)
@@ -189,8 +196,9 @@ def decode_attention_q8_pallas(
     qt = q.reshape(b, hkv, g, d).reshape(b * hkv, 1, g, d)
     kt = jnp.moveaxis(k_q, 2, 1).reshape(b * hkv, smax, d)
     vt = jnp.moveaxis(v_q, 2, 1).reshape(b * hkv, smax, dv)
-    kst = jnp.moveaxis(k_s, 2, 1).reshape(b * hkv, smax)
-    vst = jnp.moveaxis(v_s, 2, 1).reshape(b * hkv, smax)
+    # scales as (rows, 1, Smax): a (1, block_k) tile per block is TPU-tileable
+    kst = jnp.moveaxis(k_s, 2, 1).reshape(b * hkv, 1, smax)
+    vst = jnp.moveaxis(v_s, 2, 1).reshape(b * hkv, 1, smax)
     lb = jnp.broadcast_to(jnp.minimum(jnp.asarray(length, jnp.int32), smax), (b,))
     lsc = jnp.repeat(lb, hkv)[:, None]
 
@@ -201,41 +209,21 @@ def decode_attention_q8_pallas(
         kernel,
         grid=(b * hkv, 1, n_k),
         in_specs=[
-            _smem_spec(),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, g, d), lambda bh, z, j: (bh, 0, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, z, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k), lambda bh, z, j: (bh, j)),
+            pl.BlockSpec((1, 1, block_k), lambda bh, z, j: (bh, 0, j)),
             pl.BlockSpec((1, block_k, dv), lambda bh, z, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k), lambda bh, z, j: (bh, j)),
+            pl.BlockSpec((1, 1, block_k), lambda bh, z, j: (bh, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, dv), lambda bh, z, j: (bh, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b * hkv, 1, g, dv), q.dtype),
         scratch_shapes=[_vmem((g, dv)), _vmem((g, 128)), _vmem((g, 128))],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(lsc, qt, kt, kst, vt, vst)
     return out.reshape(b, hkv, g, dv).reshape(b, 1, hq, dv)
 
 
 def _vmem(shape):
-    import jax.experimental.pallas.tpu as pltpu
-
     return pltpu.VMEM(shape, jnp.float32)
-
-
-def _smem_spec():
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    except Exception:
-        return pl.BlockSpec(memory_space=pl.ANY)
-
-
-def _tpu_params(semantics):
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:
-        return None

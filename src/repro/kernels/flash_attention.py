@@ -17,6 +17,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -130,26 +131,17 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, block_q, dv), lambda bb, h, i, j: (bb, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, s, dv), q.dtype),
         scratch_shapes=[
-            pl.MemorySpace.ANY if False else _vmem((block_q, dv)),
+            _vmem((block_q, dv)),
             _vmem((block_q, 128)),
             _vmem((block_q, 128)),
         ],
-        compiler_params=_tpu_params(("parallel", "parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(qt, kt, vt)
     return jnp.moveaxis(out, 1, 2)  # back to (B, S, Hq, Dv)
 
 
 def _vmem(shape):
-    import jax.experimental.pallas.tpu as pltpu
-
     return pltpu.VMEM(shape, jnp.float32)
-
-
-def _tpu_params(semantics):
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:
-        return None

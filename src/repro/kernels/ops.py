@@ -105,6 +105,14 @@ def decode_attention(
     length,
     sliding_window: Optional[int] = None,
 ) -> jnp.ndarray:
+    # Both paths attend to every valid slot.  That equals windowed attention
+    # only when the cache holds at most one window (a ring buffer of window
+    # slots, or a max_len below the window), so a wider cache is refused.
+    if sliding_window is not None and k.shape[1] > sliding_window:
+        raise ValueError(
+            f"decode over a {k.shape[1]}-slot cache cannot apply a "
+            f"{sliding_window}-token sliding window; size the cache to the window"
+        )
     if _IMPL["mode"] == "pallas":
         from .decode_attention import decode_attention_pallas
 

@@ -5,6 +5,12 @@ scans, the sequence is tiled into L-step chunks; within a chunk everything
 is dense (chunk x chunk and chunk x state matmuls on the MXU), and the
 inter-chunk recurrence is the innermost sequential grid dimension carrying
 the (P x N) state in VMEM scratch.  Grid: (batch, heads, chunks).
+
+Layout: heads go ahead of the sequence, so every block's last two dims are
+(chunk, P) / (1, chunk) / (chunk, 1) tiles the TPU lowering accepts; the
+per-head decays ``A`` sit whole in SMEM.  Cumulative sums are masked
+reductions of the step decays, once per orientation (column for the query
+axis, row for the key axis), so the kernel needs no in-kernel transpose.
 """
 from __future__ import annotations
 
@@ -14,16 +20,18 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(
-    a_ref,  # (1,) f32 in SMEM: A for this head
-    x_ref,  # (1, chunk, 1, P)
-    dt_ref,  # (1, chunk, 1)
+    a_ref,  # (H,) f32 in SMEM: A of every head
+    x_ref,  # (1, 1, chunk, P)
+    dtr_ref,  # (1, 1, 1, chunk) f32: dt as a row
+    dtc_ref,  # (1, 1, chunk, 1) f32: dt as a column
     b_ref,  # (1, chunk, N)
     c_ref,  # (1, chunk, N)
     h0_ref,  # (1, 1, P, N) initial state
-    y_ref,  # (1, chunk, 1, P)
+    y_ref,  # (1, 1, chunk, P)
     hT_ref,  # (1, 1, P, N) final state
     state_ref,  # VMEM scratch (P, N)
     *, chunk: int, n_chunks: int,
@@ -34,43 +42,46 @@ def _ssd_kernel(
     def _init():
         state_ref[...] = h0_ref[0, 0].astype(jnp.float32)
 
-    A = a_ref[0]
-    x = x_ref[0, :, 0, :].astype(jnp.float32)  # (L, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)  # (L,)
+    A = a_ref[pl.program_id(1)]
+    x = x_ref[0, 0].astype(jnp.float32)  # (L, P)
+    dt_row = dtr_ref[0, 0]  # (1, L)
+    dt_col = dtc_ref[0, 0]  # (L, 1)
     Bm = b_ref[0].astype(jnp.float32)  # (L, N)
     Cm = c_ref[0].astype(jnp.float32)  # (L, N)
 
-    la = A * dt  # (L,)
-    cum = jnp.cumsum(la)  # inclusive
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = col <= row  # tri[t, u] = u <= t
+    la_row = A * dt_row
+    la_col = A * dt_col
+    # inclusive cumulative log decay, as a column (t) and as a row (u)
+    cum_col = jnp.sum(jnp.where(tri, la_row, 0.0), axis=1, keepdims=True)  # (L,1)
+    cum_row = jnp.sum(jnp.where(row <= col, la_col, 0.0), axis=0, keepdims=True)  # (1,L)
+    total = jnp.sum(la_col, axis=0, keepdims=True)  # (1,1) = cum at chunk end
+
     # intra-chunk: w[t,u] = (C_t.B_u) * exp(cum_t - cum_u) * dt_u,  u <= t
-    seg = cum[:, None] - cum[None, :]
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-        <= jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    )
-    decay = jnp.where(tri, jnp.exp(seg), 0.0)
+    decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
     cb = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (T,U)
-    w = cb * decay * dt[None, :]
+    w = cb * decay * dt_row
     y_intra = jax.lax.dot_general(
         w, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (T,P)
 
     # inter-chunk: y_inter[t] = exp(cum_t) * C_t @ state^T
     h_prev = state_ref[...]  # (P,N)
-    y_inter = jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y_inter = jnp.exp(cum_col) * jax.lax.dot_general(
         Cm, h_prev, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )  # (T,P)
-    y_ref[0, :, 0, :] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h = h*exp(cum_L) + sum_u exp(cum_L - cum_u) dt_u x_u B_u^T
-    tail = jnp.exp(cum[-1] - cum) * dt  # (L,)
-    xw = x * tail[:, None]  # (L,P)
+    tail = jnp.exp(total - cum_col) * dt_col  # (L,1)
     upd = jax.lax.dot_general(
-        xw, Bm, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        x * tail, Bm, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (P,N)
-    state_ref[...] = h_prev * jnp.exp(cum[-1]) + upd
+    state_ref[...] = h_prev * jnp.exp(total) + upd
 
     @pl.when(ci == n_chunks - 1)
     def _emit_state():
@@ -96,56 +107,41 @@ def ssd_scan_pallas(
     if initial_state is None:
         initial_state = jnp.zeros((bt, h, p, n), jnp.float32)
 
+    xt = jnp.moveaxis(x, 2, 1)  # (B, H, S, P)
+    dth = jnp.moveaxis(dt.astype(jnp.float32), 2, 1)  # (B, H, S)
     kernel = functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc)
     y, hT = pl.pallas_call(
         kernel,
         grid=(bt, h, nc),
         in_specs=[
-            _smem_vec_spec(),
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b_, h_, c_: (b_, c_, h_)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b_, h_, c_: (b_, h_, 0, c_)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
             pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b_, h_, c_: (b_, h_, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, p), lambda b_, h_, c_: (b_, c_, h_, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
             pl.BlockSpec((1, 1, p, n), lambda b_, h_, c_: (b_, h_, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bt, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bt, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((bt, h, p, n), jnp.float32),
         ],
-        scratch_shapes=[_vmem((p, n))],
-        compiler_params=_tpu_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(_per_head(A, h), x, dt, B, C, initial_state)
-    return y, hT
-
-
-def _per_head(A, h):
-    return A.astype(jnp.float32).reshape(h)
-
-
-def _vmem(shape):
-    import jax.experimental.pallas.tpu as pltpu
-
-    return pltpu.VMEM(shape, jnp.float32)
-
-
-def _smem_vec_spec():
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-
-        return pl.BlockSpec((1,), lambda b_, h_, c_: (h_,), memory_space=pltpu.SMEM)
-    except Exception:
-        return pl.BlockSpec((1,), lambda b_, h_, c_: (h_,))
-
-
-def _tpu_params(semantics):
-    try:
-        import jax.experimental.pallas.tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:
-        return None
+    )(
+        A.astype(jnp.float32).reshape(h),
+        xt,
+        dth[:, :, None, :],
+        dth[..., None],
+        B,
+        C,
+        initial_state,
+    )
+    return jnp.moveaxis(y, 1, 2), hT

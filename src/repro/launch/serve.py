@@ -19,6 +19,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import bundle
 from repro.serving import Engine, EngineConfig, Request
 from repro.serving.cluster import ClusterServer
@@ -94,4 +95,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

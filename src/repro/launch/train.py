@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced as reduce_cfg
 from repro.distribution import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import bundle
 from repro.training import data as data_mod
@@ -126,4 +127,5 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     raise SystemExit(main())

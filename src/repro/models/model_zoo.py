@@ -3,6 +3,7 @@ ShapeDtypeStruct input specs for every assigned input shape."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Tuple
 
 import jax
@@ -24,7 +25,10 @@ class ModelBundle:
 
     # ---- init --------------------------------------------------------------
     def init(self, key) -> Params:
-        return self.model.init(key)
+        """Random parameters, built in one compiled program so each weight
+        is drawn and cast to the model dtype without a float32 copy of the
+        layer stack (chatglm3-6b's float32 stacks would not fit in HBM)."""
+        return _jitted_init(self.cfg)(key)
 
     def param_shapes(self) -> Params:
         """ShapeDtypeStruct pytree without materializing anything."""
@@ -117,6 +121,11 @@ class ModelBundle:
         if shape.name == "long_500k":
             return self.cfg.supports_long_decode
         return True
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_init(cfg: ArchConfig):
+    return jax.jit(Model(cfg).init)
 
 
 def bundle(cfg: ArchConfig) -> ModelBundle:

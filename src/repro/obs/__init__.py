@@ -50,7 +50,7 @@ from .export import (
     write_jsonl,
     write_report,
 )
-from .host import host_snapshot
+from .host import device_snapshot, host_snapshot
 from .metrics import (
     Counter,
     Gauge,
@@ -83,6 +83,7 @@ __all__ = [
     "iter_jsonl",
     "sanitize_json",
     "write_report",
+    "device_snapshot",
     "host_snapshot",
 ]
 

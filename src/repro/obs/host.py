@@ -27,7 +27,9 @@ import logging
 import os
 from typing import Dict, List, Optional, Sequence
 
-__all__ = ["COMPETING_PATTERNS", "competing_processes", "host_snapshot"]
+__all__ = [
+    "COMPETING_PATTERNS", "competing_processes", "device_snapshot", "host_snapshot",
+]
 
 log = logging.getLogger("repro.obs.host")
 
@@ -117,3 +119,18 @@ def host_snapshot(warn: bool = True) -> Dict[str, object]:
             f"{load1:.2f}" if load1 is not None else "?", n_cpus, who,
         )
     return snap
+
+
+def device_snapshot() -> Dict[str, object]:
+    """The JAX devices a report's timings ran on, for its ``jax_device``
+    section: a timing taken on the CPU backend is not a chip number.
+
+    Keys: ``platform`` and ``device_kind`` of the first device, ``count``."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+    }

@@ -50,7 +50,7 @@ import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import get_telemetry
-from .host import host_snapshot
+from .host import device_snapshot, host_snapshot
 
 log = logging.getLogger("repro.obs.profile")
 
@@ -426,6 +426,7 @@ def run_calibration(
             "devices": [d.name for d in devices],
         },
         "host": host,
+        "jax_device": device_snapshot(),
         "devices": {},
         "kernels": [],
     }

@@ -76,18 +76,32 @@ def _next_pow2(n: int) -> int:
 class Engine:
     """One model replica serving requests with continuous batching."""
 
-    def __init__(self, bundle: ModelBundle, params, cfg: EngineConfig = EngineConfig()):
+    def __init__(
+        self,
+        bundle: ModelBundle,
+        params,
+        cfg: EngineConfig = EngineConfig(),
+        device: Optional[jax.Device] = None,
+    ):
+        """``device`` commits the replica's parameters and cache to one
+        device, so its steps run there; None leaves them on the default
+        device."""
         self.bundle = bundle
         self.model = bundle.model
-        self.params = params
         self.cfg = cfg
         mcfg = bundle.cfg
         self._recurrent = mcfg.is_recurrent
         enc_len = mcfg.frontend_len if mcfg.enc_dec else 0
+        placed = {}
+        if device is not None:
+            placed["out_shardings"] = jax.sharding.SingleDeviceSharding(device)
+            params = jax.device_put(params, device)
+        self.params = params
         self.cache = jax.jit(
             lambda: self.model.init_cache(
                 cfg.max_slots, cfg.max_len, enc_len, ragged=True
-            )
+            ),
+            **placed,
         )()
         self.queue: Deque[Request] = collections.deque()
         self.slots: List[Optional[_SlotState]] = [None] * cfg.max_slots

@@ -16,6 +16,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run_with_devices(n, code):
     env = dict(os.environ)
+    # virtual host devices: the child stays off any accelerator the parent
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = SRC
     out = subprocess.run(
@@ -70,6 +73,7 @@ def test_collective_model_matches_hlo_order_of_magnitude():
 def test_moe_ep_matches_dispatch_multidevice(mesh_shape, n_dev):
     out = _run_with_devices(n_dev, f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.models import bundle, moe as moe_mod
         from repro.distribution import sharding as shd
@@ -77,7 +81,7 @@ def test_moe_ep_matches_dispatch_multidevice(mesh_shape, n_dev):
         mb = bundle(cfg)
         params = mb.init(jax.random.key(0))
         batch = {{'tokens': jax.random.randint(jax.random.key(1), (4, 16), 1, 255)}}
-        mesh = jax.make_mesh({mesh_shape}, ('data', 'model'))
+        mesh = make_mesh({mesh_shape}, ('data', 'model'))
         with shd.use_mesh(mesh, fsdp=True):
             moe_mod.set_moe_impl('dispatch')
             l1, _ = jax.jit(mb.loss_fn)(params, batch)
@@ -96,6 +100,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
     ck = str(tmp_path / "ck")
     save_code = f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.models import bundle
         from repro.distribution import sharding as shd
@@ -103,7 +108,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
         from repro.training.checkpoint import CheckpointManager
         cfg = reduced(get_config('smollm-135m'))
         mb = bundle(cfg)
-        mesh = jax.make_mesh((4,), ('data',))
+        mesh = make_mesh((4,), ('data',))
         with shd.use_mesh(mesh, fsdp=True):
             params = mb.init(jax.random.key(7))
             ocfg = opt.AdamWConfig()
@@ -118,6 +123,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
 
     restore_code = f"""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs import get_config, reduced
         from repro.models import bundle
         from repro.distribution import sharding as shd
@@ -125,7 +131,7 @@ def test_checkpoint_elastic_reshard(tmp_path):
         from repro.training.checkpoint import CheckpointManager
         cfg = reduced(get_config('smollm-135m'))
         mb = bundle(cfg)
-        mesh = jax.make_mesh((3, 2), ('data', 'model'))  # DIFFERENT topology
+        mesh = make_mesh((3, 2), ('data', 'model'))  # DIFFERENT topology
         with shd.use_mesh(mesh, fsdp=True):
             tmpl_p = mb.param_shapes()
             ocfg = opt.AdamWConfig()

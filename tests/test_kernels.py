@@ -233,3 +233,23 @@ def test_pallas_impl_through_ops():
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     finally:
         kops.set_impl("jnp")
+
+
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+def test_decode_attention_refuses_window_narrower_than_cache(mode):
+    """Decode attends to every valid slot, so a sliding window narrower than
+    the cache is refused; a cache of at most one window is exact."""
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (2, 1, 4, 32))
+    k = jax.random.normal(ks[1], (2, 64, 2, 32))
+    v = jax.random.normal(ks[2], (2, 64, 2, 32))
+    ln = jnp.asarray([10, 64], jnp.int32)
+    try:
+        kops.set_impl(mode, interpret=True)
+        with pytest.raises(ValueError, match="sliding window"):
+            kops.decode_attention(q, k, v, ln, sliding_window=32)
+        got = kops.decode_attention(q, k, v, ln, sliding_window=64)
+    finally:
+        kops.set_impl("jnp")
+    want = ref.decode_attention_ref(q, k, v, ln)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)

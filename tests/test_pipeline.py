@@ -11,6 +11,9 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 def _run_with_devices(n, code):
     env = dict(os.environ)
+    # virtual host devices: the child stays off any accelerator the parent
+    # may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
     env["PYTHONPATH"] = SRC
     out = subprocess.run(
@@ -26,8 +29,9 @@ def test_gpipe_matches_sequential(mesh_shape, n_dev):
     axes = "('pod', 'data', 'model')" if "2, 2, 2" in mesh_shape else "('pod', 'data')"
     out = _run_with_devices(n_dev, f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distribution.pipeline import gpipe
-        mesh = jax.make_mesh({mesh_shape}, {axes})
+        mesh = make_mesh({mesh_shape}, {axes})
         S = mesh.shape['pod']
         D, L, MB, NM = 16, 8, 4, 6
         w = jax.random.normal(jax.random.key(0), (L, D, D)) * 0.3
@@ -51,8 +55,9 @@ def test_gpipe_matches_sequential(mesh_shape, n_dev):
 def test_gpipe_single_stage_fallback():
     out = _run_with_devices(2, """
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.distribution.pipeline import gpipe
-        mesh = jax.make_mesh((1, 2), ('pod', 'data'))
+        mesh = make_mesh((1, 2), ('pod', 'data'))
         D, MB, NM = 8, 4, 3
         w = jax.random.normal(jax.random.key(0), (1, 2, D, D)) * 0.3
         def stage_fn(pw, x):
