@@ -571,21 +571,12 @@ class PlacementEngine:
             "planner_latency_seconds", "wall time of one engine verb",
             labels=labels,
         ).observe(res.seconds)
-        m.counter("engine_verbs_total", "engine verb invocations",
-                  labels=labels).inc()
         if res.decision is not None:
             which = "plans_committed_total" if res.committed else "plans_rejected_total"
             m.counter(
                 which, "commit decisions by verb and deciding term",
                 labels={**labels, "term": res.decision.term or "unknown"},
             ).inc()
-        if res.cost is not None:
-            m.counter("bytes_priced_total", "bytes priced across scored plans",
-                      labels=labels).inc(float(res.cost.total_bytes))
-        if res.pending:
-            m.counter("workloads_pending_total",
-                      "workloads a verb failed to place",
-                      labels=labels).inc(float(len(res.pending)))
 
     # -- verbs -------------------------------------------------------------
     def deploy(
@@ -660,7 +651,8 @@ class PlacementEngine:
         tel = get_telemetry()
         t0 = time.time()
         with tel.tracer.span(verb) as sp:
-            before = state.clone()  # plan baseline (placement lists only)
+            with tel.tracer.span("snapshot"):
+                before = state.clone()  # plan baseline (placement lists only)
             pending: List[Workload] = []
             with state.transaction() as txn:
                 with tel.tracer.span("plan"):

@@ -369,25 +369,22 @@ class FleetFabric:
         tel = get_telemetry()
         t0 = time.perf_counter() if tel.enabled else 0.0
         refreshed = 0
-        for r, gid in enumerate(self.gids):
-            gpu = state.gpus[gid]
-            if gpu.device.name != self.kinds[self.kind_id[r]]:
-                return False
-            snap = tuple(gpu.placements)
-            if snap != self._snaps[r]:
-                self._rebuild_row(r, gpu)
-                self._snaps[r] = snap
-                refreshed += 1
-        if tel.enabled:
-            tel.metrics.histogram(
-                "fabric_refresh_seconds",
-                "per-sync cost of refreshing mutated fabric rows",
-            ).observe(time.perf_counter() - t0)
-            if refreshed:
-                tel.metrics.counter(
-                    "fabric_rows_refreshed_total",
-                    "fabric rows rebuilt from their GPUState",
-                ).inc(refreshed)
+        with tel.tracer.span("fabric.sync") as sp:
+            for r, gid in enumerate(self.gids):
+                gpu = state.gpus[gid]
+                if gpu.device.name != self.kinds[self.kind_id[r]]:
+                    return False
+                snap = tuple(gpu.placements)
+                if snap != self._snaps[r]:
+                    self._rebuild_row(r, gpu)
+                    self._snaps[r] = snap
+                    refreshed += 1
+            if tel.enabled:
+                sp.set(rows=refreshed)
+                tel.metrics.histogram(
+                    "fabric_refresh_seconds",
+                    "per-sync cost of refreshing mutated fabric rows",
+                ).observe(time.perf_counter() - t0)
         return True
 
     def _refresh_row(self, r: int) -> None:

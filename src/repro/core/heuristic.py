@@ -19,6 +19,7 @@ from __future__ import annotations
 import math
 from typing import List, Sequence, Tuple
 
+from ..obs import get_telemetry
 from .baselines import place_max_utilization
 from .state import ClusterState, GPUState, Workload
 
@@ -89,9 +90,16 @@ def _vacate(state: ClusterState, gid: str, targets: Sequence[str]) -> bool:
 
 
 def compaction(state: ClusterState) -> List[Workload]:
-    """Vacate underutilized GPUs (paper Sec 4.2 compaction steps 1-3)."""
+    """Vacate underutilized GPUs (paper Sec 4.2 compaction steps 1-3).
+
+    With telemetry enabled, adds its counts to the open span (the engine
+    verb's ``plan``): ``passes``, ``vacate_attempts``, ``vacated`` (GPUs
+    emptied in the kept layout), ``precheck_skips`` and ``borrow_fallbacks``.
+    """
+    passes = attempts = vacated_total = skips = borrows = 0
     progress = True
     while progress:
+        passes += 1
         progress = False
         # Step 1: sort allocated GPUs by joint slice utilization ascending.
         used = sorted(
@@ -110,8 +118,11 @@ def compaction(state: ClusterState) -> List[Workload]:
                 if state.gpus[o].memory_occupancy()[-1] is None
             )
             if have < need:
+                skips += 1
                 continue
+            attempts += 1
             if _vacate(state, gpu.gid, others):
+                vacated_total += 1
                 progress = True
                 break
         if progress:
@@ -122,6 +133,7 @@ def compaction(state: ClusterState) -> List[Workload]:
         if not free:
             continue
         borrowed = free[0].gid
+        borrows += 1
         with state.transaction() as outer:
             vacated = 0
             used = sorted(
@@ -131,12 +143,21 @@ def compaction(state: ClusterState) -> List[Workload]:
                 targets = [
                     g.gid for g in state.used_gpus() if g.gid != gpu.gid
                 ] + [borrowed]
+                attempts += 1
                 if _vacate(state, gpu.gid, targets):
                     vacated += 1
             if vacated > 1:
+                vacated_total += vacated
                 progress = True
             else:
                 outer.rollback()
+    tel = get_telemetry()
+    if tel.enabled and tel.tracer.current is not None:
+        attrs = tel.tracer.current.attrs
+        for key, n in (("passes", passes), ("vacate_attempts", attempts),
+                       ("vacated", vacated_total), ("precheck_skips", skips),
+                       ("borrow_fallbacks", borrows)):
+            attrs[key] = attrs.get(key, 0) + n
     return []
 
 

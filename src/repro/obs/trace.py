@@ -11,6 +11,12 @@ Two record kinds flow through a :class:`Tracer`:
   *arbitrary* clock, used for simulated-time marks like migration windows
   and autoscale decisions where wall time is meaningless.
 
+While a live :class:`Tracer` holds a span open it also holds a
+``jax.profiler.TraceAnnotation("repro/<name>")`` open, so under a running
+profiler every span lands on the trace's host plane, on the same clock as
+the device's operations.  JAX is imported when a live ``Tracer`` is made:
+``repro.obs`` itself stays importable without it.
+
 The default process-global tracer is a :class:`NoopTracer`: ``span()``
 returns a shared singleton whose ``__enter__``/``__exit__``/``set`` do
 nothing, so instrumentation left in hot paths costs one attribute lookup and
@@ -22,9 +28,12 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Span", "SpanEvent", "Tracer", "NoopTracer", "NOOP_SPAN"]
+
+#: a span named ``x`` appears in a profiler trace as ``repro/x``
+PROFILER_PREFIX = "repro/"
 
 
 @dataclasses.dataclass
@@ -50,12 +59,14 @@ class Span:
     """One wall-time interval in a trace tree.
 
     Used as a context manager (via :meth:`Tracer.span`); ``set(**attrs)``
-    attaches attributes at any point while open or after close.
+    attaches attributes at any point while open or after close.  The start
+    is kept on two clocks: ``start_unix`` (``time.time()``) and
+    ``start_perf`` (``time.perf_counter()``, the clock ``duration`` is on).
     """
 
     __slots__ = (
-        "name", "span_id", "parent_id", "trace_id",
-        "start_unix", "duration", "attrs", "_tracer", "_t0", "status",
+        "name", "span_id", "parent_id", "trace_id", "start_unix", "start_perf",
+        "duration", "attrs", "_tracer", "_annotation", "status",
     )
 
     def __init__(
@@ -73,8 +84,10 @@ class Span:
         self.parent_id = parent_id
         self.trace_id = trace_id
         self.attrs: Dict[str, Any] = attrs or {}
+        self._annotation = tracer._annotation(PROFILER_PREFIX + name)
+        self._annotation.__enter__()
         self.start_unix = time.time()
-        self._t0 = time.perf_counter()
+        self.start_perf = time.perf_counter()
         self.duration = 0.0
         self.status = "ok"
 
@@ -86,7 +99,8 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        self.duration = time.perf_counter() - self._t0
+        self.duration = time.perf_counter() - self.start_perf
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.status = "error"
             self.attrs.setdefault("error", exc_type.__name__)
@@ -101,6 +115,7 @@ class Span:
             "parent_id": self.parent_id,
             "trace_id": self.trace_id,
             "start_unix": self.start_unix,
+            "start_perf": self.start_perf,
             "duration_s": self.duration,
             "status": self.status,
             "attrs": dict(self.attrs),
@@ -113,6 +128,10 @@ class Tracer:
     enabled = True
 
     def __init__(self, max_records: int = 200_000):
+        from jax.profiler import TraceAnnotation
+
+        #: opened for the life of each span, named ``repro/<span name>``
+        self._annotation = TraceAnnotation
         #: drop-oldest cap so unbounded runs cannot exhaust memory.
         self.max_records = max_records
         self.spans: List[Span] = []
@@ -201,8 +220,8 @@ class NoopTracer:
     """Default tracer: every operation is a constant-time no-op."""
 
     enabled = False
-    spans: List[Span] = []
-    events: List[SpanEvent] = []
+    spans: Tuple[Span, ...] = ()
+    events: Tuple[SpanEvent, ...] = ()
 
     def span(self, name: str, **attrs: Any) -> _NoopSpan:
         return NOOP_SPAN

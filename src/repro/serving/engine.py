@@ -15,6 +15,13 @@ JetStream-style slot architecture on top of the model zoo:
 
 This is the workload the paper places: one Engine == one model replica in a
 MIG/pod partition.  serving/cluster.py binds engines to placements.
+
+Telemetry (``repro.obs``, no-op unless enabled): ``replica.submit`` (rid),
+``replica.step``, ``replica.prefill`` (rid, prompt_len, bucket; pad,
+dispatch, first-token fetch) with its child ``replica.insert``, and
+``replica.decode`` (n_active) with the children ``replica.decode.prepare``
+(slot scan, device index pull, input arrays), ``replica.decode.fetch`` (the
+wait for the sampled tokens) and ``replica.decode.commit`` (appends, retire).
 """
 from __future__ import annotations
 
@@ -28,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.model_zoo import ModelBundle
+from ..obs import get_telemetry
 from .kvcache import insert_prefix
 
 __all__ = ["Request", "Completion", "Engine", "EngineConfig"]
@@ -126,12 +134,13 @@ class Engine:
 
     # ------------------------------------------------------------------ API
     def submit(self, req: Request) -> None:
-        if len(req.prompt) + req.max_new_tokens > self.cfg.max_len:
-            raise ValueError(
-                f"{req.rid}: prompt+max_new={len(req.prompt)}+{req.max_new_tokens} "
-                f"exceeds max_len={self.cfg.max_len}"
-            )
-        self.queue.append(req)
+        with get_telemetry().tracer.span("replica.submit", rid=req.rid):
+            if len(req.prompt) + req.max_new_tokens > self.cfg.max_len:
+                raise ValueError(
+                    f"{req.rid}: prompt+max_new={len(req.prompt)}+{req.max_new_tokens} "
+                    f"exceeds max_len={self.cfg.max_len}"
+                )
+            self.queue.append(req)
 
     @property
     def n_active(self) -> int:
@@ -146,8 +155,9 @@ class Engine:
 
         Returns the number of tokens produced this step (incl. the first
         token each admitted request gets from its prefill logits)."""
-        produced = self._admit()
-        return produced + self._decode_step()
+        with get_telemetry().tracer.span("replica.step"):
+            produced = self._admit()
+            return produced + self._decode_step()
 
     def run(self, max_steps: int = 100_000) -> List[Completion]:
         for _ in range(max_steps):
@@ -180,15 +190,18 @@ class Engine:
             if (self.cfg.bucket_prefill and not self._recurrent)
             else plen
         )
-        toks = np.zeros((1, pad), np.int32)
-        toks[0, :plen] = req.prompt
-        batch = {"tokens": jnp.asarray(toks), **req.extras}
-        logits, prefix = self._prefill(self.params, batch)
-        # first generated token: logits at the LAST TRUE prompt position
-        first = int(jnp.argmax(logits[0, plen - 1, :]))
-        self.cache = insert_prefix(
-            self.cache, prefix, jnp.int32(slot_id), jnp.int32(plen)
-        )
+        tracer = get_telemetry().tracer
+        with tracer.span("replica.prefill", rid=req.rid, prompt_len=plen, bucket=pad):
+            toks = np.zeros((1, pad), np.int32)
+            toks[0, :plen] = req.prompt
+            batch = {"tokens": jnp.asarray(toks), **req.extras}
+            logits, prefix = self._prefill(self.params, batch)
+            # first generated token: logits at the LAST TRUE prompt position
+            first = int(jnp.argmax(logits[0, plen - 1, :]))
+            with tracer.span("replica.insert"):
+                self.cache = insert_prefix(
+                    self.cache, prefix, jnp.int32(slot_id), jnp.int32(plen)
+                )
         # account for the first token: it is appended by the next decode
         # step's write (its KV is not in the cache yet; decode writes it).
         return first
@@ -197,31 +210,36 @@ class Engine:
         active = [i for i, s in enumerate(self.slots) if s is not None]
         if not active:
             return 0
-        tokens = np.zeros((self.cfg.max_slots, 1), np.int32)
-        lengths = np.zeros((self.cfg.max_slots,), np.int32)
-        for i, st in enumerate(self.slots):
-            if st is not None:
-                tokens[i, 0] = st.generated[-1]
-                lengths[i] = st.length - 1  # position OF the fed token
-        # inactive slots: keep device/host index agreement by feeding their
-        # device-side index (the model bumps every slot's index by 1).
-        dev_idx = np.asarray(self._slot_indexes())
-        for i in range(self.cfg.max_slots):
-            if self.slots[i] is None:
-                lengths[i] = dev_idx[i]
-        nxt, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(lengths)
-        )
-        nxt = np.asarray(nxt)
-        produced = 0
-        self.stats["decode_steps"] += 1
-        for i in active:
-            st = self.slots[i]
-            st.generated.append(int(nxt[i]))
-            st.length += 1
-            produced += 1
-            self.stats["tokens"] += 1
-            self._retire_if_done(i)
+        tracer = get_telemetry().tracer
+        with tracer.span("replica.decode", n_active=len(active)):
+            with tracer.span("replica.decode.prepare"):
+                tokens = np.zeros((self.cfg.max_slots, 1), np.int32)
+                lengths = np.zeros((self.cfg.max_slots,), np.int32)
+                for i, st in enumerate(self.slots):
+                    if st is not None:
+                        tokens[i, 0] = st.generated[-1]
+                        lengths[i] = st.length - 1  # position OF the fed token
+                # inactive slots: keep device/host index agreement by feeding
+                # their device-side index (the model bumps every slot's index
+                # by 1).
+                dev_idx = np.asarray(self._slot_indexes())
+                for i in range(self.cfg.max_slots):
+                    if self.slots[i] is None:
+                        lengths[i] = dev_idx[i]
+                tokens, lengths = jnp.asarray(tokens), jnp.asarray(lengths)
+            nxt, self.cache = self._decode(self.params, self.cache, tokens, lengths)
+            with tracer.span("replica.decode.fetch"):
+                nxt = np.asarray(nxt)
+            with tracer.span("replica.decode.commit"):
+                produced = 0
+                self.stats["decode_steps"] += 1
+                for i in active:
+                    st = self.slots[i]
+                    st.generated.append(int(nxt[i]))
+                    st.length += 1
+                    produced += 1
+                    self.stats["tokens"] += 1
+                    self._retire_if_done(i)
         return produced
 
     def _slot_indexes(self) -> np.ndarray:

@@ -9,21 +9,35 @@ Covers the PR-5 observability guarantees:
 - ``Histogram.percentile`` matches ``numpy.percentile`` linear
   interpolation on the raw reservoir;
 - exporters: Prometheus text exposition shape, strict (NaN-free) JSONL
-  round-trip, and the ``repro.obs.report`` renderer.
+  round-trip, and the ``repro.obs.report`` renderer;
+- live spans land in a profiler trace as ``repro/<name>``; the replica
+  engine, fabric sync and compaction spans and counters are where the work
+  is, and served tokens and compaction layouts are the same on and off.
 """
 import json
 import math
+import os
+import subprocess
+import sys
+import time
+from glob import glob
+from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro import obs
 from repro.core.engine import CommitPolicy, PlacementEngine
 from repro.core.events import OnlineSimulator, build_fleet, generate_trace
 from repro.core.profiles import A100_80GB
+from repro.core.simulator import generate_test_case
 from repro.core.state import ClusterState, Workload
 from repro.core.tpu_profiles import TPU_V5E_POD
 from repro.obs import report
+
+SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
 @pytest.fixture(autouse=True)
@@ -70,6 +84,10 @@ class TestTelemetryIsInert:
         assert json.dumps(obs.sanitize_json(d_on), sort_keys=True) == \
             json.dumps(obs.sanitize_json(d_off), sort_keys=True)
 
+    def test_noop_tracer_holds_no_shared_mutable_state(self):
+        tr = obs.NoopTracer()
+        assert tr.spans == () and tr.events == ()  # immutable, never a shared list
+
     def test_disabled_telemetry_records_nothing(self):
         obs.disable()
         tel = obs.get_telemetry()
@@ -106,6 +124,14 @@ class TestSpanTrees:
             assert c.trace_id == root.trace_id
         assert root.attrs["committed"] is True
         assert root.attrs["n_moves"] == res.plan.n_moves
+
+    def test_snapshot_plan_score_commit_partition_a_compaction(self):
+        tel = obs.enable()
+        PlacementEngine("rule_based").compact(self._state())
+        root = tel.tracer.find(name="compact")[0]
+        kids = sorted(tel.tracer.children_of(root), key=lambda c: c.start_perf)
+        assert [c.name for c in kids] == ["snapshot", "plan", "score", "commit"]
+        assert sum(c.duration for c in kids) <= root.duration
 
     def test_rejected_plan_has_rollback_child_and_term(self):
         tel = obs.enable()
@@ -225,3 +251,118 @@ class TestReport:
         text = html.read_text()
         assert text.lstrip().lower().startswith("<!doctype html>")
         assert "deploy" in text
+
+
+def _xplane_events(trace_dir):
+    """(name, duration s) of every event on the host planes of a trace."""
+    [path] = glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(ev.name, ev.duration_ns * 1e-9) for plane in pd.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for ev in line.events]
+
+
+class TestProfilerClock:
+    def test_live_span_lands_on_the_host_plane(self, tmp_path):
+        tel = obs.enable()
+        jax.profiler.start_trace(str(tmp_path))
+        t0 = time.perf_counter()
+        with tel.tracer.span("probe") as sp:
+            time.sleep(0.02)
+        jax.profiler.stop_trace()
+        assert t0 <= sp.start_perf <= t0 + sp.duration
+        assert sp.as_dict()["start_perf"] == sp.start_perf
+        got = [d for name, d in _xplane_events(tmp_path) if name == "repro/probe"]
+        assert len(got) == 1 and 0.02 <= got[0] <= sp.duration + 0.01
+
+    def test_obs_imports_and_runs_disabled_without_jax(self):
+        code = ("import sys; sys.modules['jax'] = None\n"
+                "from repro import obs\n"
+                "with obs.get_telemetry().tracer.span('x') as sp:\n"
+                "    sp.set(a=1)\n"
+                "print('ok')")
+        out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                             text=True, timeout=60,
+                             env={**os.environ, "PYTHONPATH": SRC})
+        assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+    def test_disabled_tracer_annotates_nothing(self, tmp_path):
+        jax.profiler.start_trace(str(tmp_path))
+        with obs.get_telemetry().tracer.span("probe"):
+            time.sleep(0.005)
+        jax.profiler.stop_trace()
+        assert not any(n.startswith("repro/") for n, _ in _xplane_events(tmp_path))
+
+
+def _serve(prompts):
+    from repro.configs import get_config, reduced
+    from repro.models import bundle
+    from repro.serving import Engine, EngineConfig, Request
+
+    mb = bundle(reduced(get_config("smollm-135m")))
+    eng = Engine(mb, mb.init(jax.random.key(0)), EngineConfig(max_slots=2, max_len=64))
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=f"r{i}", prompt=p, max_new_tokens=3 + i))
+    return {c.rid: c.tokens for c in eng.run()}
+
+
+class TestReplicaSpans:
+    PROMPTS = [[5, 9, 2], [7] * 11, [3, 1, 4, 1, 5], [8, 8]]
+
+    def test_spans_per_request_and_per_round_tokens_unchanged(self):
+        off = _serve(self.PROMPTS)
+        tel = obs.enable()
+        on = _serve(self.PROMPTS)
+        obs.disable()
+        assert on == off and len(on) == len(self.PROMPTS)
+        tr = tel.tracer
+        rids = {f"r{i}" for i in range(len(self.PROMPTS))}
+        assert sorted(s.attrs["rid"] for s in tr.find("replica.submit")) == sorted(rids)
+        prefills = tr.find("replica.prefill")
+        assert sorted(s.attrs["rid"] for s in prefills) == sorted(rids)
+        for s in prefills:
+            plen = len(self.PROMPTS[int(s.attrs["rid"][1:])])
+            assert s.attrs["prompt_len"] == plen and s.attrs["bucket"] >= plen
+            assert [c.name for c in tr.children_of(s)] == ["replica.insert"]
+        by_rid = {s.attrs["rid"]: s for s in tr.find("replica.submit")}
+        assert all(p.start_perf > by_rid[p.attrs["rid"]].start_perf for p in prefills)
+        steps = {s.span_id for s in tr.find("replica.step")}
+        decodes = tr.find("replica.decode")
+        assert decodes and all(d.parent_id in steps for d in decodes)
+        assert all(p.parent_id in steps for p in prefills)
+        for d in decodes:
+            kids = sorted(tr.children_of(d), key=lambda c: c.start_perf)
+            assert [c.name for c in kids] == [
+                "replica.decode.prepare", "replica.decode.fetch", "replica.decode.commit"]
+            assert 1 <= d.attrs["n_active"] <= 2
+
+
+class TestPlacementCounters:
+    def test_compaction_counts_and_layout_unchanged(self):
+        case = generate_test_case(3, n_gpus=24)
+        off = case.initial.clone()
+        PlacementEngine("rule_based").compact(off)
+        tel = obs.enable()
+        on = case.initial.clone()
+        PlacementEngine("rule_based").compact(on)
+        obs.disable()
+        assert _snapshot(on) == _snapshot(off)
+        [plan] = [s for s in tel.tracer.find("plan")
+                  if tel.tracer.find("compact")[0].span_id == s.parent_id]
+        a = plan.attrs
+        assert a["passes"] >= 1 and a["vacate_attempts"] >= a["vacated"] >= 1
+        freed = len(case.initial.used_gpus()) - len(on.used_gpus())
+        assert a["vacated"] - a["borrow_fallbacks"] <= freed <= a["vacated"]
+        assert a["precheck_skips"] >= 0
+
+    def test_fabric_sync_span_counts_refreshed_rows(self):
+        case = generate_test_case(5, n_gpus=16)
+        state, (first, *rest) = case.initial, case.new_workloads
+        eng = PlacementEngine("rule_based", fabric="on")
+        eng.deploy(state, [first])  # builds the mirror: nothing to sync
+        tel = obs.enable()
+        eng.deploy(state, rest[:1])
+        syncs = tel.tracer.find("fabric.sync")
+        assert len(syncs) == 1 and syncs[0].attrs["rows"] == 1
+        deploy = tel.tracer.find("deploy")[0]
+        assert syncs[0].trace_id == deploy.span_id
