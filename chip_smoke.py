@@ -293,14 +293,14 @@ def phase_kernels(attn_cfgs=None, q8_cfg=None, ssd_cfg=None, *, seq: int = 1024,
             flash_attention_pallas(q, k, v, interpret=interpret),
             ref.attention_ref, q, k, v, tol=ATTN_TOL)
         q = normal((4, 1, hq, d))
-        k, v = normal((4, cache_len, hkv, d)), normal((4, cache_len, hkv, d))
+        k, v = normal((4, hkv, d, cache_len)), normal((4, hkv, d, cache_len))
         errs[f"decode/{cfg.name}"] = _max_err(
             decode_attention_pallas(q, k, v, lens, interpret=interpret),
             ref.decode_attention_ref, q, k, v, lens, tol=ATTN_TOL)
     hq, hkv, d = q8_cfg.n_heads, q8_cfg.n_kv_heads, q8_cfg.head_dim_
     q = normal((4, 1, hq, d))
-    kq, ks = ref.quantize_kv(normal((4, cache_len, hkv, d)))
-    vq, vs = ref.quantize_kv(normal((4, cache_len, hkv, d)))
+    kq, ks = ref.quantize_kv(normal((4, hkv, d, cache_len)), axis=-2)
+    vq, vs = ref.quantize_kv(normal((4, hkv, d, cache_len)), axis=-2)
     errs[f"decode_q8/{q8_cfg.name}"] = _max_err(
         decode_attention_q8_pallas(q, kq, ks, vq, vs, lens, interpret=interpret),
         ref.decode_attention_q8_ref, q, kq, ks, vq, vs, lens, tol=ATTN_TOL)

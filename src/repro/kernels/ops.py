@@ -104,23 +104,32 @@ def decode_attention(
     v: jnp.ndarray,
     length,
     sliding_window: Optional[int] = None,
+    layer=None,
+    new=None,
 ) -> jnp.ndarray:
+    """q (B,1,Hq,D) against a sequence-minor cache k (B,Hkv,D,Smax), v
+    (B,Hkv,Dv,Smax).  With ``layer``, k/v are layer stacks (L,B,Hkv,D,Smax)
+    and the call attends over layer ``layer`` without slicing it out.
+    ``new`` = (k_new, v_new, pos): this step's token, attended to in place
+    of cache slot ``pos`` (ref.decode_attention_ref)."""
     # Both paths attend to every valid slot.  That equals windowed attention
     # only when the cache holds at most one window (a ring buffer of window
     # slots, or a max_len below the window), so a wider cache is refused.
-    if sliding_window is not None and k.shape[1] > sliding_window:
+    if sliding_window is not None and k.shape[-1] > sliding_window:
         raise ValueError(
-            f"decode over a {k.shape[1]}-slot cache cannot apply a "
+            f"decode over a {k.shape[-1]}-slot cache cannot apply a "
             f"{sliding_window}-token sliding window; size the cache to the window"
         )
     if _IMPL["mode"] == "pallas":
         from .decode_attention import decode_attention_pallas
 
         return decode_attention_pallas(
-            q, k, v, length=length, interpret=_IMPL["interpret"]
+            q, k, v, length=length, interpret=_IMPL["interpret"], layer=layer, new=new
         )
     with jax.named_scope("pallas_decode_attention"):
-        return _ref.decode_attention_ref(q, k, v, length, sliding_window)
+        if layer is not None:  # a dynamic index that XLA fuses into the reads
+            k, v = k[layer], v[layer]
+        return _ref.decode_attention_ref(q, k, v, length, sliding_window, new)
 
 
 def decode_attention_q8(
@@ -130,18 +139,24 @@ def decode_attention_q8(
     v_q: jnp.ndarray,
     v_s: jnp.ndarray,
     length,
+    layer=None,
+    new=None,
 ) -> jnp.ndarray:
     """int8-KV flash decoding: HBM KV reads halve; dequant happens per VMEM
     tile inside the kernel (beyond-paper serving lever, EXPERIMENTS.md §Perf
-    Cell C)."""
+    Cell C).  Layouts, ``layer`` and ``new`` (its token dequantized) as in
+    ``decode_attention``."""
     if _IMPL["mode"] == "pallas":
         from .decode_attention import decode_attention_q8_pallas
 
         return decode_attention_q8_pallas(
-            q, k_q, k_s, v_q, v_s, length=length, interpret=_IMPL["interpret"]
+            q, k_q, k_s, v_q, v_s, length=length, interpret=_IMPL["interpret"],
+            layer=layer, new=new,
         )
     with jax.named_scope("pallas_decode_attention_q8"):
-        return _ref.decode_attention_q8_ref(q, k_q, k_s, v_q, v_s, length)
+        if layer is not None:
+            k_q, k_s, v_q, v_s = (a[layer] for a in (k_q, k_s, v_q, v_s))
+        return _ref.decode_attention_q8_ref(q, k_q, k_s, v_q, v_s, length, new)
 
 
 def cross_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:

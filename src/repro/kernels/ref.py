@@ -49,63 +49,89 @@ def decode_attention_ref(
     v: jnp.ndarray,
     length,
     sliding_window: Optional[int] = None,
+    new=None,
 ) -> jnp.ndarray:
-    """One-token decode: q (B,1,Hq,D) vs ring-buffer cache k/v (B,Smax,Hkv,D).
+    """One-token decode: q (B,1,Hq,D) vs ring-buffer cache k (B,Hkv,D,Smax),
+    v (B,Hkv,Dv,Smax) — the serving cache's sequence-minor layout.
 
     Valid cache slots are arange(Smax) < length (ring buffers pass
     length >= Smax once wrapped, making every slot valid — attention is
     order-invariant so slot order does not matter).  ``length`` may be a
     scalar (uniform batch) or a (B,) vector (ragged continuous batching).
+
+    ``new`` = (k_new (B,Hkv,D), v_new (B,Hkv,Dv), pos): this step's token,
+    not yet in the cache.  Slot ``pos`` (scalar or (B,)), which the token is
+    about to overwrite, is left out, and the token is attended to as well —
+    the same as writing it at ``pos`` and attending to ``length + 1`` slots.
     """
     b, sq, hq, d = q.shape
-    smax, hkv = k.shape[1], k.shape[2]
-    dv = v.shape[-1]
+    hkv, smax = k.shape[1], k.shape[-1]
+    dv = v.shape[2]
     g = hq // hkv
     qg = q.reshape(b, sq, hkv, g, d)
+    scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     # f32 ACCUMULATION over bf16 operands (preferred_element_type), never a
     # wholesale astype(f32) of k/v — that materializes an f32 shadow of the
     # entire KV cache, which XLA then carries through the decode loop.
+    # (k first: XLA's CPU runtime refuses bf16 x bf16 -> f32 with q first)
     scores = jnp.einsum(
-        "bqhgd,bkhd->bhgqk", qg, k, preferred_element_type=jnp.float32
-    )
-    scores = scores / jnp.sqrt(d).astype(jnp.float32)
+        "bhdk,bqhgd->bhgqk", k, qg, preferred_element_type=jnp.float32
+    ) * scale
     lim = jnp.broadcast_to(jnp.minimum(jnp.asarray(length), smax), (b,))
     valid = jnp.arange(smax)[None, :] < lim[:, None]  # (B, Smax)
+    if new is not None:
+        k_new, v_new, pos = new
+        valid &= jnp.arange(smax)[None, :] != jnp.broadcast_to(pos, (b,))[:, None]
+        s_new = jnp.einsum(
+            "bqhgd,bhd->bhgq", qg, k_new, preferred_element_type=jnp.float32
+        )[..., None] * scale  # (B,Hkv,G,Sq,1)
     scores = jnp.where(valid[:, None, None, None, :], scores, _NEG)
-    p = jnp.exp(scores - scores.max(-1, keepdims=True))
-    p = p / p.sum(-1, keepdims=True)
+    m = scores.max(-1, keepdims=True)
+    if new is not None:
+        m = jnp.maximum(m, s_new)
+    p = jnp.exp(scores - m)
+    denom = p.sum(-1, keepdims=True)
+    if new is not None:
+        p_new = jnp.exp(s_new - m)
+        denom = denom + p_new
     out = jnp.einsum(
-        "bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
+        "bhgqk,bhdk->bqhgd", (p / denom).astype(v.dtype), v,
         preferred_element_type=jnp.float32,
     )
+    if new is not None:
+        w_new = jnp.moveaxis((p_new / denom)[..., 0], 3, 1)  # (B,Sq,Hkv,G)
+        out = out + w_new[..., None] * v_new.astype(jnp.float32)[:, None, :, None, :]
     return out.reshape(b, sq, hq, dv).astype(q.dtype)
 
 
 def quantize_kv(k: jnp.ndarray, axis: int = -1):
     """Per-(token, head) symmetric int8 quantization of a KV tensor.
 
-    k (B,S,Hkv,D) -> (q int8 (B,S,Hkv,D), scale f32 (B,S,Hkv))."""
+    k (B,S,Hkv,D) -> (q int8 (B,S,Hkv,D), scale f32 (B,S,Hkv)); ``axis`` is
+    the head-dim axis that a scale covers."""
     amax = jnp.max(jnp.abs(k.astype(jnp.float32)), axis=axis)
     scale = jnp.maximum(amax, 1e-8) / 127.0
     q = jnp.clip(
-        jnp.round(k.astype(jnp.float32) / scale[..., None]), -127, 127
+        jnp.round(k.astype(jnp.float32) / jnp.expand_dims(scale, axis)), -127, 127
     ).astype(jnp.int8)
     return q, scale
 
 
 def decode_attention_q8_ref(
     q: jnp.ndarray,  # (B,1,Hq,D)
-    k_q: jnp.ndarray,  # (B,Smax,Hkv,D) int8
-    k_s: jnp.ndarray,  # (B,Smax,Hkv) f32
+    k_q: jnp.ndarray,  # (B,Hkv,D,Smax) int8
+    k_s: jnp.ndarray,  # (B,Hkv,Smax) f32
     v_q: jnp.ndarray,
     v_s: jnp.ndarray,
     length,
+    new=None,
 ) -> jnp.ndarray:
     """int8-KV decode oracle: dequantize then run the fp oracle.  The Pallas
-    kernel dequantizes per VMEM tile instead — HBM reads HALVE."""
-    k = k_q.astype(jnp.float32) * k_s[..., None]
-    v = v_q.astype(jnp.float32) * v_s[..., None]
-    return decode_attention_ref(q, k, v, length)
+    kernel dequantizes per VMEM tile instead — HBM reads HALVE.  ``new`` as
+    in ``decode_attention_ref``, its token already dequantized."""
+    k = k_q.astype(jnp.float32) * k_s[:, :, None, :]
+    v = v_q.astype(jnp.float32) * v_s[:, :, None, :]
+    return decode_attention_ref(q, k, v, length, new=new)
 
 
 def ssd_scan_ref(

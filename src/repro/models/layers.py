@@ -125,6 +125,94 @@ def _split_heads(x, n_heads, hd):
     return x.reshape(b, s, n_heads, hd)
 
 
+# Every cache leaf keeps its sequence axis LAST: K/V are (B, Hkv, D, S),
+# int8 scales (B, Hkv, S), MLA's latents (B, R, S).  That is the layout the
+# TPU stores them in and the decode attention reads, so a one-token write is
+# one lane column per slot and head, and nothing is relaid out around it.
+def _seq_minor(a: jnp.ndarray) -> jnp.ndarray:
+    """A fresh (B, S, ...) block in the cache layout (B, ..., S)."""
+    return jnp.moveaxis(a, 1, -1)
+
+
+def _layer_of(buf: jnp.ndarray, layer) -> jnp.ndarray:
+    """Layer ``layer`` of a layer-stacked cache leaf; the leaf itself when
+    ``layer`` is None.  Inside the decode loop this is a dynamic index into
+    the group's stack, which XLA fuses into the op that reads it."""
+    return buf if layer is None else buf[layer]
+
+
+def _put(buf: jnp.ndarray, val: jnp.ndarray, pos) -> jnp.ndarray:
+    """Write the block ``val`` (..., s) at sequence position ``pos`` of
+    ``buf`` (..., Smax)."""
+    start = (0,) * (val.ndim - 1) + (pos,)
+    return jax.lax.dynamic_update_slice(buf, val.astype(buf.dtype), start)
+
+
+def _put_rows(cache: Params, rows, new: Params, stacked: bool = False) -> Params:
+    """Ragged one-token write: for each slot b, ``new[n][b]`` (...) into
+    leaf ``n`` of ``cache`` (B, ..., Smax) at sequence position ``rows[b]``;
+    with ``stacked``, ``new[n]`` (L, B, ...) into every layer of a layer
+    stack (L, B, ..., Smax) at once.
+
+    A loop over the slots rewrites, in every leaf, the tile of up to 128
+    positions that holds the slot's position, with the token set in its
+    lane.  Tiles and positions stay sequence-minor: XLA's scatter of single
+    positions would store the cache token-major instead, and its gather of
+    tiles would relay out the whole stack."""
+    names = list(new)
+    slot = 1 if stacked else 0
+
+    def one(b, leaves):
+        out = []
+        for n, buf in zip(names, leaves):
+            x = jnp.expand_dims(jnp.take(new[n], b, axis=slot), slot)
+            smax = buf.shape[-1]
+            w = min(128, smax)
+            t0 = jnp.minimum(rows[b] - rows[b] % w, smax - w)
+            start = [0] * buf.ndim
+            start[slot], start[-1] = b, t0
+            size = list(buf.shape)
+            size[slot], size[-1] = 1, w
+            tile = jax.lax.dynamic_slice(buf, start, size)
+            tile = jnp.where(
+                jnp.arange(w) == rows[b] - t0, x.astype(buf.dtype)[..., None], tile
+            )
+            out.append(jax.lax.dynamic_update_slice(buf, tile, start))
+        return out
+
+    leaves = jax.lax.fori_loop(0, rows.shape[0], one, [cache[n] for n in names])
+    return dict(zip(names, leaves))
+
+
+def _decode_write(cache: Params, new: Params, wr, idx, layer) -> Params:
+    """After a decode step's attention, the token ``new`` (leaf -> (B, ...,
+    1) block) goes to sequence position ``wr``: written now, or, inside the
+    decode layer loop (``layer`` given), handed back for ``commit_tokens``."""
+    tok = {n: a[..., 0] for n, a in new.items()}
+    if layer is not None:
+        return {"new": tok, "pos": wr, "index": idx + 1}
+    if jnp.ndim(idx) == 1:
+        upd = _put_rows(cache, wr, tok)
+    else:
+        upd = {n: _put(cache[n], a, wr) for n, a in new.items()}
+    return {**cache, **upd, "index": idx + 1}
+
+
+def commit_tokens(cache: Params, pending: Params) -> Params:
+    """Write the tokens that a decode step's layer loop handed back (stacked
+    over the group's layers by the loop) into the group's stacked cache, all
+    layers in one pass over the slots.  Every layer of a group holds the same
+    per-slot index (prefill, insert and decode write them together), so the
+    first layer's write positions serve them all."""
+    pos = pending["pos"][0]
+    toks = pending["new"]
+    if jnp.ndim(pos) == 1:
+        upd = _put_rows(cache, pos, toks, stacked=True)
+    else:
+        upd = {n: _put(cache[n], a[..., None], pos) for n, a in toks.items()}
+    return {**cache, **upd, "index": pending["index"]}
+
+
 def attention(
     p: Params,
     x: jnp.ndarray,
@@ -132,13 +220,18 @@ def attention(
     positions: jnp.ndarray,
     cache: Optional[Params] = None,
     kv_x: Optional[jnp.ndarray] = None,
+    layer: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """GQA self- or cross-attention.
 
     cache: None (training/prefill without cache) or
-           {"k","v" (B,Smax,Hkv,Dh), "index" scalar} for decode; the updated
-           cache is returned.  kv_x: encoder states for cross-attention
+           {"k","v" (B,Hkv,Dh,Smax), "index" scalar or (B,)} for decode; the
+           updated cache is returned.  kv_x: encoder states for cross-attention
            (cache then holds precomputed K/V; positions ignored for K).
+    layer: when given, every cache leaf carries a leading layer axis and this
+           call reads and writes layer ``layer`` of it in place: a decode step
+           writes only the new token, and attends over the stack's layer
+           without slicing it out (models/transformer._scan_group).
     """
     hd = cfg.head_dim_
     b, s, _ = x.shape
@@ -165,87 +258,57 @@ def attention(
     from ..kernels import ops as kops
 
     if cache is not None and not cross:
-        idx = cache["index"]
-        smax = cache["k"].shape[1]
+        idx = _layer_of(cache["index"], layer)
+        smax = cache["k"].shape[-1]
         ring = bool(cfg.sliding_window) and smax == cfg.sliding_window
         quant = "k_s" in cache  # int8 KV cache (set_kv_quant)
-        if s == 1:
-            # decode: append one token into the (ring) buffer, attend to all
+        if quant:
             from ..kernels.ref import quantize_kv
 
+            k_w, ks_w = quantize_kv(k)
+            v_w, vs_w = quantize_kv(v)
+            new = {"k": k_w, "k_s": ks_w, "v": v_w, "v_s": vs_w}
+        elif ring and s >= smax:
+            # prefill past the window: the ring holds its last smax tokens,
+            # rolled so that token t sits at t % smax
+            r = s % smax
+            new = {"k": jnp.roll(k[:, -smax:], r, axis=1),
+                   "v": jnp.roll(v[:, -smax:], r, axis=1)}
+        else:
+            new = {"k": k, "v": v}
+        new = {n: _seq_minor(a) for n, a in new.items()}
+        if s == 1:
+            # decode: attend to the cache's valid slots and to this step's
+            # token, which takes slot wr (the ring's oldest, or the clamped
+            # last slot) — the same as writing it first and attending to
+            # idx + 1 slots
+            wr = idx % smax if ring else jnp.minimum(idx, smax - 1)
             if quant:
-                k_w, ks_w = quantize_kv(k)
-                v_w, vs_w = quantize_kv(v)
-            else:
-                k_w, v_w, ks_w, vs_w = k, v, None, None
-            if jnp.ndim(idx) == 1:
-                # ragged continuous batching: per-slot write position/length
-                wr = idx % smax if ring else jnp.minimum(idx, smax - 1)
-                bix = jnp.arange(b)
-                ck = cache["k"].at[bix, wr].set(k_w[:, 0].astype(cache["k"].dtype))
-                cv = cache["v"].at[bix, wr].set(v_w[:, 0].astype(cache["v"].dtype))
-                if quant:
-                    cks = cache["k_s"].at[bix, wr].set(ks_w[:, 0])
-                    cvs = cache["v_s"].at[bix, wr].set(vs_w[:, 0])
-            else:
-                wr = idx % smax if ring else idx
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], k_w.astype(cache["k"].dtype), (0, wr, 0, 0)
+                k_new, v_new = (
+                    new[n][..., 0].astype(jnp.float32) * new[n + "_s"]
+                    for n in ("k", "v")
                 )
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], v_w.astype(cache["v"].dtype), (0, wr, 0, 0)
+                out = kops.decode_attention_q8(
+                    q, cache["k"], cache["k_s"], cache["v"], cache["v_s"],
+                    length=idx, layer=layer, new=(k_new, v_new, wr),
                 )
-                if quant:
-                    cks = jax.lax.dynamic_update_slice(
-                        cache["k_s"], ks_w, (0, wr, 0)
-                    )
-                    cvs = jax.lax.dynamic_update_slice(
-                        cache["v_s"], vs_w, (0, wr, 0)
-                    )
-            cache = {**cache, "k": ck, "v": cv, "index": idx + 1}
-            if quant:
-                cache.update(k_s=cks, v_s=cvs)
-                out = kops.decode_attention_q8(q, ck, cks, cv, cvs, length=idx + 1)
             else:
+                k_new, v_new = (new[n][..., 0].astype(cache[n].dtype) for n in ("k", "v"))
                 out = kops.decode_attention(
-                    q, ck, cv, length=idx + 1, sliding_window=cfg.sliding_window
+                    q, cache["k"], cache["v"], length=idx,
+                    sliding_window=cfg.sliding_window, layer=layer,
+                    new=(k_new, v_new, wr),
                 )
+            cache = _decode_write(cache, new, wr, idx, layer)
         else:
             # prefill (from an empty cache): causal attention over the fresh
             # block; keys/values recorded into the cache for later decode.
+            wr = 0 if ring and s >= smax else idx
+            upd = {n: _put(cache[n], a, wr) for n, a in new.items()}
+            cache = {**cache, **upd, "index": idx + s}
             out = kops.flash_attention(
                 q, k, v, causal=True, sliding_window=cfg.sliding_window
             )
-            if quant:
-                from ..kernels.ref import quantize_kv
-
-                kq_b, ks_b = quantize_kv(k)
-                vq_b, vs_b = quantize_kv(v)
-                ck = jax.lax.dynamic_update_slice(
-                    cache["k"], kq_b, (0, idx, 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    cache["v"], vq_b, (0, idx, 0, 0)
-                )
-                cks = jax.lax.dynamic_update_slice(cache["k_s"], ks_b, (0, idx, 0))
-                cvs = jax.lax.dynamic_update_slice(cache["v_s"], vs_b, (0, idx, 0))
-                cache = {**cache, "k": ck, "v": cv, "k_s": cks, "v_s": cvs,
-                         "index": idx + s}
-            else:
-                if ring and s >= smax:
-                    r = s % smax
-                    kw = jnp.roll(k[:, -smax:], r, axis=1).astype(cache["k"].dtype)
-                    vw = jnp.roll(v[:, -smax:], r, axis=1).astype(cache["v"].dtype)
-                    ck = kw
-                    cv = vw
-                else:
-                    ck = jax.lax.dynamic_update_slice(
-                        cache["k"], k.astype(cache["k"].dtype), (0, idx, 0, 0)
-                    )
-                    cv = jax.lax.dynamic_update_slice(
-                        cache["v"], v.astype(cache["v"].dtype), (0, idx, 0, 0)
-                    )
-                cache = {**cache, "k": ck, "v": cv, "index": idx + s}
     elif cross:
         out = kops.cross_attention(q, k, v)
     else:
@@ -286,10 +349,11 @@ def mla_attention(
     cfg: ArchConfig,
     positions: jnp.ndarray,
     cache: Optional[Params] = None,
+    layer: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Params]]:
     """Multi-head Latent Attention.  The cache stores only the compressed
     latent c_kv (kv_lora_rank) and the shared rotary key k_pe — DeepSeek-V3's
-    memory saving, reproduced exactly."""
+    memory saving, reproduced exactly.  ``layer`` as in ``attention``."""
     b, s, _ = x.shape
     nope, rope_d, vh = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     h = cfg.n_heads
@@ -307,51 +371,45 @@ def mla_attention(
 
     from ..kernels import ops as kops
 
+    decode = cache is not None and s == 1
     if cache is not None:
-        idx = cache["index"]
-        if jnp.ndim(idx) == 1:
-            # ragged continuous batching (s == 1): per-slot write position
-            smax0 = cache["c_kv"].shape[1]
-            wr = jnp.minimum(idx, smax0 - 1)
-            bix = jnp.arange(b)
-            c_all = cache["c_kv"].at[bix, wr].set(
-                c_kv[:, 0].astype(cache["c_kv"].dtype)
-            )
-            pe_all = cache["k_pe"].at[bix, wr].set(
-                k_pe[:, 0, 0, :].astype(cache["k_pe"].dtype)
-            )
-        else:
-            c_all = jax.lax.dynamic_update_slice(
-                cache["c_kv"], c_kv.astype(cache["c_kv"].dtype), (0, idx, 0)
-            )
-            pe_all = jax.lax.dynamic_update_slice(
-                cache["k_pe"], k_pe[:, :, 0, :].astype(cache["k_pe"].dtype), (0, idx, 0)
-            )
-        cache = {**cache, "c_kv": c_all, "k_pe": pe_all, "index": idx + s}
-        smax = c_all.shape[1]
-    if cache is not None and s > 1:
-        # prefill: cache recorded above; attention over the fresh block only
-        cache_for_math = None
-    else:
-        cache_for_math = cache
-    if cache_for_math is not None:
+        idx = _layer_of(cache["index"], layer)
+        new = {"c_kv": _seq_minor(c_kv), "k_pe": _seq_minor(k_pe[:, :, 0, :])}
+        smax = cache["c_kv"].shape[-1]
+        if not decode:
+            # prefill: record the block; attention over the fresh block only
+            upd = {n: _put(cache[n], a, idx) for n, a in new.items()}
+            cache = {**cache, **upd, "index": idx + s}
+    if decode:
         # ---- decode with weight ABSORPTION (DeepSeek-V3's trick) ----------
         # Never decompress the latent cache: fold wkv_b's key half into the
-        # query and apply its value half after attending over the latents.
+        # query and apply its value half after attending over the latents:
+        # the cache's valid slots and this step's latent, which takes slot
+        # wr (as in ``attention``).
+        f32 = jnp.float32
         wb = p["wkv_b"].reshape(cfg.kv_lora_rank, h, nope + vh)
         wb_k, wb_v = wb[..., :nope], wb[..., nope:]
-        q_eff = jnp.einsum("bshn,rhn->bshr", q_nope.astype(jnp.float32), wb_k.astype(jnp.float32))
-        scores = jnp.einsum("bshr,btr->bhst", q_eff, c_all.astype(jnp.float32))
-        scores += jnp.einsum(
-            "bshd,btd->bhst", q_pe.astype(jnp.float32), pe_all.astype(jnp.float32)
-        )
-        scores = scores / jnp.sqrt(jnp.float32(nope + rope_d))
-        lim = jnp.broadcast_to(idx + s, (b,))
-        valid = jnp.arange(smax)[None, None, None, :] < lim[:, None, None, None]
-        scores = jnp.where(valid, scores, -1e30)
-        pr = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhst,btr->bshr", pr, c_all.astype(jnp.float32))
-        out = jnp.einsum("bshr,rhv->bshv", ctx, wb_v.astype(jnp.float32)).astype(x.dtype)
+        q_eff = jnp.einsum("bshn,rhn->bshr", q_nope.astype(f32), wb_k.astype(f32))
+        c_all, pe_all = (_layer_of(cache[n], layer).astype(f32) for n in ("c_kv", "k_pe"))
+        c_new, pe_new = (new[n][..., 0].astype(cache[n].dtype).astype(f32)
+                         for n in ("c_kv", "k_pe"))
+        scale = 1.0 / jnp.sqrt(f32(nope + rope_d))
+        scores = (jnp.einsum("bshr,brt->bhst", q_eff, c_all)
+                  + jnp.einsum("bshd,bdt->bhst", q_pe.astype(f32), pe_all)) * scale
+        s_new = (jnp.einsum("bshr,br->bhs", q_eff, c_new)
+                 + jnp.einsum("bshd,bd->bhs", q_pe.astype(f32), pe_new))[..., None] * scale
+        wr = jnp.minimum(idx, smax - 1)
+        lim, wr_b = (jnp.broadcast_to(a, (b,))[:, None, None, None]
+                     for a in (jnp.minimum(idx, smax), wr))
+        t = jnp.arange(smax)[None, None, None, :]
+        scores = jnp.where((t < lim) & (t != wr_b), scores, -1e30)
+        m = jnp.maximum(scores.max(-1, keepdims=True), s_new)
+        pr, p_new = jnp.exp(scores - m), jnp.exp(s_new - m)
+        denom = pr.sum(-1, keepdims=True) + p_new
+        ctx = jnp.einsum("bhst,brt->bshr", pr / denom, c_all)
+        ctx = ctx + jnp.moveaxis(p_new / denom, 1, 2) * c_new[:, None, None, :]
+        out = jnp.einsum("bshr,rhv->bshv", ctx, wb_v.astype(f32)).astype(x.dtype)
+        cache = _decode_write(cache, new, wr, idx, layer)
     else:
         # ---- train / prefill: decompress K/V (dense MXU matmuls) ----------
         kv = (c_kv @ p["wkv_b"]).reshape(b, s, h, nope + vh)

@@ -86,16 +86,18 @@ def _apply_block(
     cfg: ArchConfig,
     positions: jnp.ndarray,
     cache: Optional[Params],
+    layer: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Params], jnp.ndarray]:
-    """Returns (x_out, new_cache, aux_loss)."""
+    """Returns (x_out, new_cache, aux_loss).  With ``layer`` (a decode step
+    inside the layer loop), ``cache`` is the group's whole layer stack, read
+    in place, and new_cache only what layer ``layer`` changed: its pending
+    token (models/layers.commit_tokens) or a recurrent layer's new state."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attn", "moe"):
         h = layers.apply_norm(p["ln1"], x, cfg.norm)
         attn_cache = cache["attn"] if cache is not None else None
-        if cfg.attention == "mla":
-            a, attn_cache = layers.mla_attention(p["attn"], h, cfg, positions, attn_cache)
-        else:
-            a, attn_cache = layers.attention(p["attn"], h, cfg, positions, attn_cache)
+        attend = layers.mla_attention if cfg.attention == "mla" else layers.attention
+        a, attn_cache = attend(p["attn"], h, cfg, positions, attn_cache, layer=layer)
         x = x + a
         h = layers.apply_norm(p["ln2"], x, cfg.norm)
         if kind == "moe":
@@ -108,6 +110,8 @@ def _apply_block(
     # recurrent mixers
     h = layers.apply_norm(p["ln1"], x, cfg.norm)
     mix_state = cache["mixer"] if cache is not None else None
+    if layer is not None:
+        mix_state = jax.tree.map(lambda a: a[layer], mix_state)
     fn = {"mamba2": ssm.mamba2_block, "mlstm": ssm.mlstm_block, "slstm": ssm.slstm_block}[kind]
     y, mix_state = fn(p["mixer"], h, cfg, mix_state)
     x = x + y
@@ -119,28 +123,27 @@ def _apply_block(
 # caches
 # ---------------------------------------------------------------------------
 def _attn_cache(cfg: ArchConfig, batch: int, max_len: int, dtype, ragged=False):
+    """One layer's cache.  Every leaf but ``index`` keeps its sequence axis
+    last (models/layers.py says why)."""
     idx = jnp.zeros((batch,) if ragged else (), jnp.int32)
     if cfg.attention == "mla":
         return {
-            "c_kv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), dtype),
-            "k_pe": jnp.zeros((batch, max_len, cfg.qk_rope_head_dim), dtype),
+            "c_kv": jnp.zeros((batch, cfg.kv_lora_rank, max_len), dtype),
+            "k_pe": jnp.zeros((batch, cfg.qk_rope_head_dim, max_len), dtype),
             "index": idx,
         }
     s = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
+    kv = (batch, cfg.n_kv_heads, cfg.head_dim_, s)
     if layers.kv_quant_enabled() and not cfg.sliding_window:
         # int8 KV + per-(token, head) scales (serving lever, §Perf C3)
         return {
-            "k": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim_), jnp.int8),
-            "k_s": jnp.zeros((batch, s, cfg.n_kv_heads), jnp.float32),
-            "v": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim_), jnp.int8),
-            "v_s": jnp.zeros((batch, s, cfg.n_kv_heads), jnp.float32),
+            "k": jnp.zeros(kv, jnp.int8),
+            "k_s": jnp.zeros((batch, cfg.n_kv_heads, s), jnp.float32),
+            "v": jnp.zeros(kv, jnp.int8),
+            "v_s": jnp.zeros((batch, cfg.n_kv_heads, s), jnp.float32),
             "index": idx,
         }
-    return {
-        "k": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim_), dtype),
-        "v": jnp.zeros((batch, s, cfg.n_kv_heads, cfg.head_dim_), dtype),
-        "index": idx,
-    }
+    return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype), "index": idx}
 
 
 def _block_cache(kind: str, cfg: ArchConfig, batch: int, max_len: int, dtype,
@@ -355,7 +358,25 @@ class Model:
     def _scan_group(self, gp, gc, x, kind: str, positions):
         cfg = self.cfg
         aux0 = jnp.zeros((), jnp.float32)
+        if gc is not None and x.shape[1] == 1:
+            # decode: each layer reads its cache in place from the group's
+            # stack, which the loop never slices out or restacks, and hands
+            # back only what it changed.  After the loop every layer's new
+            # token lands in the stack in one pass (a recurrent group's new
+            # states simply are its cache).
+            def body_dec(carry, xs):
+                xx, auxc = carry
+                bp, li = xs
+                xx, changed, aux = _apply_block(bp, xx, kind, cfg, positions, gc, layer=li)
+                return (xx, auxc + aux), changed
+
+            n = jax.tree.leaves(gp)[0].shape[0]
+            (x, aux), changed = jax.lax.scan(body_dec, (x, aux0), (gp, jnp.arange(n)))
+            if "attn" in changed:
+                changed = {"attn": layers.commit_tokens(gc["attn"], changed["attn"])}
+            return x, changed, aux
         if gc is not None:
+            # prefill: every layer writes a whole fresh block into its cache
             def body(carry, xs):
                 xx, auxc = carry
                 bp, bc = xs

@@ -208,8 +208,8 @@ def _decode_workload(b: int, smax: int, hq: int, hkv: int, d: int) -> _Workload:
     def make():
         key = jax.random.key(0)
         q = jax.random.normal(key, (b, 1, hq, d), jnp.float32)
-        k = jax.random.normal(key, (b, smax, hkv, d), jnp.float32)
-        v = jax.random.normal(key, (b, smax, hkv, d), jnp.float32)
+        k = jax.random.normal(key, (b, hkv, d, smax), jnp.float32)
+        v = jax.random.normal(key, (b, hkv, d, smax), jnp.float32)
         lens = jnp.full((b,), smax // 2, jnp.int32)
         fn = jax.jit(lambda q, k, v, l: ops.decode_attention(q, k, v, l))
         return fn, (q, k, v, lens)

@@ -38,7 +38,7 @@ from ..models.model_zoo import ModelBundle
 from ..obs import get_telemetry
 from .kvcache import insert_prefix
 
-__all__ = ["Request", "Completion", "Engine", "EngineConfig"]
+__all__ = ["Request", "Completion", "Engine", "EngineConfig", "decode_program"]
 
 
 @dataclasses.dataclass
@@ -79,6 +79,22 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+def decode_program(model):
+    """The replica's jitted ragged decode step: feed one token per slot at
+    its position, return each slot's greedy next token and the cache.  The
+    cache is donated, and the step writes each slot's new K/V into it in
+    place (models/transformer._scan_group)."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def _decode(params, cache, tokens, lengths):
+        logits, cache, _ = model.forward(
+            params, {"tokens": tokens}, cache=cache, positions=lengths[:, None]
+        )
+        return jnp.argmax(logits[:, -1, :], axis=-1), cache
+
+    return _decode
 
 
 class Engine:
@@ -122,15 +138,8 @@ class Engine:
             logits, cache = bundle.prefill_fn(params, batch, max_len=cfg.max_len)
             return logits, cache
 
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def _decode(params, cache, tokens, lengths):
-            logits, cache, _ = self.model.forward(
-                params, {"tokens": tokens}, cache=cache, positions=lengths[:, None]
-            )
-            return jnp.argmax(logits[:, -1, :], axis=-1), cache
-
         self._prefill = _prefill
-        self._decode = _decode
+        self._decode = decode_program(self.model)
 
     # ------------------------------------------------------------------ API
     def submit(self, req: Request) -> None:

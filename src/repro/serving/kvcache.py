@@ -184,4 +184,7 @@ def paged_decode_attention(
     from ..kernels import ops as kops
 
     k, v = cache.gather_batch(block_tables)
-    return kops.decode_attention(q, k, v, length=lengths)
+    # pages are token-major; decode attention reads (B, Hkv, D, S)
+    return kops.decode_attention(
+        q, jnp.moveaxis(k, 1, -1), jnp.moveaxis(v, 1, -1), length=lengths
+    )

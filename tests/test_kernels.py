@@ -75,8 +75,8 @@ def test_flash_attention_noncausal():
 def test_decode_attention_sweep(dtype, b, smax, hq, hkv, d, length, bk):
     ks = jax.random.split(jax.random.key(3), 3)
     q = jax.random.normal(ks[0], (b, 1, hq, d)).astype(dtype)
-    k = jax.random.normal(ks[1], (b, smax, hkv, d)).astype(dtype)
-    v = jax.random.normal(ks[2], (b, smax, hkv, d)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, hkv, d, smax)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, hkv, d, smax)).astype(dtype)
     ln = jnp.array(length, jnp.int32)
     got = decode_attention_pallas(q, k, v, length=ln, block_k=bk, interpret=True)
     want = ref.decode_attention_ref(q, k, v, length=ln)
@@ -99,10 +99,10 @@ def test_decode_attention_q8_sweep(b, smax, hq, hkv, d, length, bk):
 
     ks = jax.random.split(jax.random.key(8), 3)
     q = jax.random.normal(ks[0], (b, 1, hq, d))
-    k = jax.random.normal(ks[1], (b, smax, hkv, d))
-    v = jax.random.normal(ks[2], (b, smax, hkv, d))
-    kq, ksc = ref.quantize_kv(k)
-    vq, vsc = ref.quantize_kv(v)
+    k = jax.random.normal(ks[1], (b, hkv, d, smax))
+    v = jax.random.normal(ks[2], (b, hkv, d, smax))
+    kq, ksc = ref.quantize_kv(k, axis=-2)
+    vq, vsc = ref.quantize_kv(v, axis=-2)
     ln = jnp.array(length, jnp.int32)
     got = decode_attention_q8_pallas(q, kq, ksc, vq, vsc, length=ln,
                                      block_k=bk, interpret=True)
@@ -120,14 +120,73 @@ def test_decode_attention_q8_ragged():
     ks = jax.random.split(jax.random.key(9), 3)
     b, smax, hq, hkv, d = 3, 256, 8, 2, 64
     q = jax.random.normal(ks[0], (b, 1, hq, d))
-    k = jax.random.normal(ks[1], (b, smax, hkv, d))
-    v = jax.random.normal(ks[2], (b, smax, hkv, d))
-    kq, ksc = ref.quantize_kv(k)
-    vq, vsc = ref.quantize_kv(v)
+    k = jax.random.normal(ks[1], (b, hkv, d, smax))
+    v = jax.random.normal(ks[2], (b, hkv, d, smax))
+    kq, ksc = ref.quantize_kv(k, axis=-2)
+    vq, vsc = ref.quantize_kv(v, axis=-2)
     lens = jnp.asarray([7, 256, 100], jnp.int32)
     got = decode_attention_q8_pallas(q, kq, ksc, vq, vsc, length=lens,
                                      block_k=64, interpret=True)
     want = ref.decode_attention_q8_ref(q, kq, ksc, vq, vsc, length=lens)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+def test_decode_attention_reads_layer_of_stack(mode, quant):
+    """Given ``layer``, decode attention over a (L, B, Hkv, D, S) stack equals
+    attention over that layer alone, in both kernel modes."""
+    ks = jax.random.split(jax.random.key(12), 3)
+    n_layers, b, smax, hq, hkv, d = 3, 2, 256, 8, 2, 64
+    q = jax.random.normal(ks[0], (b, 1, hq, d))
+    k = jax.random.normal(ks[1], (n_layers, b, hkv, d, smax))
+    v = jax.random.normal(ks[2], (n_layers, b, hkv, d, smax))
+    lens = jnp.asarray([77, 256], jnp.int32)
+    layer = jnp.int32(1)
+    try:
+        kops.set_impl(mode, interpret=True)
+        if quant:
+            (kq, ksc), (vq, vsc) = ref.quantize_kv(k, axis=-2), ref.quantize_kv(v, axis=-2)
+            got = kops.decode_attention_q8(q, kq, ksc, vq, vsc, lens, layer=layer)
+            want = ref.decode_attention_q8_ref(q, kq[1], ksc[1], vq[1], vsc[1], lens)
+        else:
+            got = kops.decode_attention(q, k, v, lens, layer=layer)
+            want = ref.decode_attention_ref(q, k[1], v[1], lens)
+    finally:
+        kops.set_impl("jnp")
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+@pytest.mark.parametrize("mode", ["jnp", "pallas"])
+def test_decode_attention_new_token_equals_written(mode, quant):
+    """A token not yet in the cache, attended to in place of slot ``pos``,
+    equals writing it at ``pos`` first and attending to one more slot: a
+    fresh slot, the ring's oldest slot once wrapped, and the clamped last
+    slot of a full cache."""
+    ks = jax.random.split(jax.random.key(13), 5)
+    b, smax, hq, hkv, d = 3, 256, 8, 2, 64
+    q = jax.random.normal(ks[0], (b, 1, hq, d))
+    k = jax.random.normal(ks[1], (b, hkv, d, smax))
+    v = jax.random.normal(ks[2], (b, hkv, d, smax))
+    k_new = jax.random.normal(ks[3], (b, hkv, d))
+    v_new = jax.random.normal(ks[4], (b, hkv, d))
+    lens = jnp.asarray([77, 300, 256], jnp.int32)  # old tokens per slot
+    pos = jnp.asarray([77, 300 % smax, smax - 1], jnp.int32)
+    written = [x.at[jnp.arange(b), :, :, pos].set(n) for x, n in ((k, k_new), (v, v_new))]
+    try:
+        kops.set_impl(mode, interpret=True)
+        if quant:
+            (kq, ksc), (vq, vsc) = (ref.quantize_kv(x, axis=-2) for x in written)
+            deq = [(xq[jnp.arange(b), :, :, pos] * xs[jnp.arange(b), :, pos][..., None])
+                   for xq, xs in ((kq, ksc), (vq, vsc))]
+            got = kops.decode_attention_q8(q, kq, ksc, vq, vsc, lens, new=(*deq, pos))
+            want = ref.decode_attention_q8_ref(q, kq, ksc, vq, vsc, lens + 1)
+        else:
+            got = kops.decode_attention(q, k, v, lens, new=(k_new, v_new, pos))
+            want = ref.decode_attention_ref(q, *written, lens + 1)
+    finally:
+        kops.set_impl("jnp")
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
@@ -136,8 +195,8 @@ def test_decode_attention_ragged_pallas():
     ks = jax.random.split(jax.random.key(10), 3)
     b, smax, hq, hkv, d = 4, 256, 8, 2, 64
     q = jax.random.normal(ks[0], (b, 1, hq, d))
-    k = jax.random.normal(ks[1], (b, smax, hkv, d))
-    v = jax.random.normal(ks[2], (b, smax, hkv, d))
+    k = jax.random.normal(ks[1], (b, hkv, d, smax))
+    v = jax.random.normal(ks[2], (b, hkv, d, smax))
     lens = jnp.asarray([1, 64, 137, 256], jnp.int32)
     got = decode_attention_pallas(q, k, v, length=lens, block_k=64, interpret=True)
     want = ref.decode_attention_ref(q, k, v, length=lens)
@@ -241,8 +300,8 @@ def test_decode_attention_refuses_window_narrower_than_cache(mode):
     the cache is refused; a cache of at most one window is exact."""
     ks = jax.random.split(jax.random.key(11), 3)
     q = jax.random.normal(ks[0], (2, 1, 4, 32))
-    k = jax.random.normal(ks[1], (2, 64, 2, 32))
-    v = jax.random.normal(ks[2], (2, 64, 2, 32))
+    k = jax.random.normal(ks[1], (2, 2, 32, 64))
+    v = jax.random.normal(ks[2], (2, 2, 32, 64))
     ln = jnp.asarray([10, 64], jnp.int32)
     try:
         kops.set_impl(mode, interpret=True)
